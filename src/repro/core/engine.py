@@ -55,6 +55,21 @@ ulp-level sharding tolerance; the single-device path never enters the
 sharded body and stays the bit-reference).  See ROADMAP.md
 §"Client-sharding contract".
 
+**Phase names.**  Each phase of the round carries one ``jax.named_scope``,
+which lands in the compiled HLO's op metadata and so in a profiler trace's
+device operations: ``mmfl.stats`` (loss probe, needs-all gradient norms),
+``mmfl.sample`` (probabilities, draw, masks, Sec. 3.3 monitors; the loss
+all-gather when sharded), ``mmfl.local_train`` (cohort gather and local
+SGD, the all-client training of needs-all methods too), ``mmfl.aggregate``
+(coefficients, fault inject and guard, the strategy's aggregation and its
+kernels and psum) and ``mmfl.evaluate`` (test accuracy).  An operation
+belongs to the innermost of these in its name stack.  On the host,
+``rollout`` and ``evaluate`` run under the ``TraceAnnotation`` spans
+``mmfl.rollout`` (with its number of ``rounds``) and ``mmfl.evaluate``;
+``bench/trace_scopes.py`` reduces such a trace to device time per phase.
+Scopes only name operations: results and compiles are the same with or
+without them.
+
 ``repro.core.server.MMFLServer`` is a thin stateful facade over this module
 (attribute views like ``h_valid``/``beta_state`` preserved); the strategy
 protocol is unchanged (``repro.core.methods``).
@@ -68,6 +83,7 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import (convergence, faults, methods, sampling, sharding,
@@ -488,8 +504,9 @@ class RoundEngine:
             self._loss_all = self._stats_pure = self._round_pure = None
             self._g_stats = self._g_round = None
             self.loss_all_jit = None
-        self.eval_jit = [jax.jit(lambda params, test, acc=t.model.accuracy:
-                                 acc(params, test)) for t in self.tasks]
+        self.eval_jit = [jax.jit(jax.named_scope("mmfl.evaluate")(
+            lambda params, test, acc=t.model.accuracy: acc(params, test)))
+            for t in self.tasks]
         # the input state is donated: the [N, params] stale stores /
         # all-client update buffers update in place instead of doubling
         # peak memory (tests/test_task_fusion.py asserts the donation);
@@ -590,6 +607,7 @@ class RoundEngine:
             G = jax.tree.map(lambda a, b: a - b, params, pf)
             return G, l0
 
+        @jax.named_scope("mmfl.local_train")
         def local_all(params, keys, data, lr, corr=None):
             """vmap over the cohort's clients -> (G [A,...], losses [A])."""
             if corr is None:
@@ -647,6 +665,7 @@ class RoundEngine:
         loss_all = loss_all or self._loss_all[s]
         local_all = local_all or self._local_all[s]
 
+        @jax.named_scope("mmfl.stats")
         def stats_fn(params, data, key, lr, explicit_data=False):
             # explicit_data=False -> the probe slice bound at build time
             # (in-trace slicing of the closed-over dataset would
@@ -685,6 +704,7 @@ class RoundEngine:
         local_all = local_all or self._local_all[s]
         fault_model, guard_on = self.fault_model, self.fault_guard
 
+        @jax.named_scope("mmfl.aggregate")
         def round_fn(params, state, train_in, p_col, act_v,
                      data, lr, round_idx, view=None, fault=None):
             """``train_in`` is the task's PRNG key (cohort methods train
@@ -708,10 +728,11 @@ class RoundEngine:
                 # argsort is stable, so a padded world (trailing inactive
                 # zeros) gathers the same cohort; slot-keyed randomness
                 # (index_keys) makes the draw capacity-invariant.
-                idx = jnp.argsort(-act_client)[:cohort]
-                keys = sampling.index_keys(train_in, cohort)
-                data_c = jax.tree.map(lambda x: x[idx], data)
-                corr = strat.local_correction(state, idx)
+                with jax.named_scope("mmfl.local_train"):
+                    idx = jnp.argsort(-act_client)[:cohort]
+                    keys = sampling.index_keys(train_in, cohort)
+                    data_c = jax.tree.map(lambda x: x[idx], data)
+                    corr = strat.local_correction(state, idx)
                 G, _ = local_all(params, keys, data_c, lr, corr)
                 coeff, act = coeff_client[idx], act_client[idx]
             fault_counts = None
@@ -804,6 +825,7 @@ class RoundEngine:
                     stale.batched_tree_dot(G, G), 0.0))
             return losses, G, norms
 
+        @jax.named_scope("mmfl.stats")
         def stats_g(params_g, data_g, keys_g, lr, explicit=False):
             px, py = ((data_g["x"][:, :, :take], data_g["y"][:, :, :take])
                       if explicit else (probe_x, probe_y))
@@ -984,6 +1006,7 @@ class RoundEngine:
                     stale.batched_tree_dot(G, G), 0.0))
             return losses, G, norms
 
+        @jax.named_scope("mmfl.stats")
         def stats_g(params_g, data_g, keys_g, lr, off):
             if len(grp) == 1:
                 sq = lambda t: jax.tree.map(lambda a: a[0], t)
@@ -1017,6 +1040,7 @@ class RoundEngine:
         axis = sharding.CLIENT_AXIS
         fault_model, guard_on = self.fault_model, self.fault_guard
 
+        @jax.named_scope("mmfl.aggregate")
         def round_one(params, state, train_in, p_col, act_v, data,
                       lr, round_idx, view, off, fault=None):
             d_col, d_v_col, B_v, proc, cmask = view    # replicated [N]/[V]
@@ -1035,12 +1059,13 @@ class RoundEngine:
                 rank = jnp.cumsum(acts_i) - acts_i           # [N] exact
                 rank_loc = sl(rank)
                 in_cohort = act_loc * (rank_loc < cohort)
-                idx = jnp.argsort(-in_cohort)[:cohort_loc]
-                slot_keys = jax.vmap(
-                    lambda i: jax.random.fold_in(train_in, i))(
-                    rank_loc[idx])
-                data_c = jax.tree.map(lambda x: x[idx], data)
-                corr = strat.local_correction(state, idx)
+                with jax.named_scope("mmfl.local_train"):
+                    idx = jnp.argsort(-in_cohort)[:cohort_loc]
+                    slot_keys = jax.vmap(
+                        lambda i: jax.random.fold_in(train_in, i))(
+                        rank_loc[idx])
+                    data_c = jax.tree.map(lambda x: x[idx], data)
+                    corr = strat.local_correction(state, idx)
                 G, _ = local_all(params, slot_keys, data_c, lr, corr)
                 coeff = coeff_loc[idx] * in_cohort[idx]
                 act = in_cohort[idx]
@@ -1139,38 +1164,42 @@ class RoundEngine:
             stats = [g_stats[g](state.params[g], data[g],
                                 task_keys[np.asarray(grp)], lr, off)
                      for g, grp in enumerate(groups)]
-            losses_loc = self._to_task_cols([st[0] for st in stats],
-                                            n=n_loc)           # [n_loc,S]
-            losses_ns = jax.lax.all_gather(losses_loc, axis, axis=0,
-                                           tiled=True)         # [N,S] repl
-            norms_ns = None
-            if strat.needs_grad_norms:
-                norms_ns = jax.lax.all_gather(
-                    self._to_task_cols([st[2] for st in stats], n=n_loc),
-                    axis, axis=0, tiled=True)
+            with jax.named_scope("mmfl.sample"):
+                losses_loc = self._to_task_cols(
+                    [st[0] for st in stats], n=n_loc)          # [n_loc,S]
+                losses_ns = jax.lax.all_gather(
+                    losses_loc, axis, axis=0, tiled=True)      # [N,S] repl
+                norms_ns = None
+                if strat.needs_grad_norms:
+                    norms_ns = jax.lax.all_gather(
+                        self._to_task_cols([st[2] for st in stats],
+                                           n=n_loc),
+                        axis, axis=0, tiled=True)
 
-            # ---- 2) sampling (replicated: every shard computes the same
-            # [V,S] arrays from the same gathered columns) ----------------
-            ctx = self.sampler_ctx(state.round)
-            if self.probabilities_hook is not None:
-                p = self.probabilities_hook(ctx, losses_ns, norms_ns)
-            else:
-                p = strat.probabilities(ctx, losses_ns, norms_ns)
-            p = p * proc_mask[:, None]
-            active = strat.sample(k_sample, p, ctx, losses_ns)
-            active = active * proc_mask[:, None]
+                # ---- 2) sampling (replicated: every shard computes the
+                # same [V,S] arrays from the same gathered columns) -------
+                ctx = self.sampler_ctx(state.round)
+                if self.probabilities_hook is not None:
+                    p = self.probabilities_hook(ctx, losses_ns, norms_ns)
+                else:
+                    p = strat.probabilities(ctx, losses_ns, norms_ns)
+                p = p * proc_mask[:, None]
+                active = strat.sample(k_sample, p, ctx, losses_ns)
+                active = active * proc_mask[:, None]
 
-            # ---- 3) Sec. 3.3 monitors (the single-device subgraph on the
-            # replicated sampling arrays: bitwise the unsharded metrics) --
-            metrics = self.sampling_metrics(p, active, losses_ns)
+                # ---- 3) Sec. 3.3 monitors (the single-device subgraph on
+                # the replicated sampling arrays: bitwise the unsharded
+                # metrics) ------------------------------------------------
+                metrics = self.sampling_metrics(p, active, losses_ns)
 
             # ---- 4) per-group round on local blocks ---------------------
             fault_loc = None
             if self.faulty:
                 # shard-local fault columns: offset-keyed draws reproduce
                 # the single-device fault world block-for-block
-                fault_loc = self._fault_cols(state.key, state.round,
-                                             n=n_loc, offset=off)
+                with jax.named_scope("mmfl.aggregate"):
+                    fault_loc = self._fault_cols(state.key, state.round,
+                                                 n=n_loc, offset=off)
             new_params, new_mstate, beta_parts = [], [], []
             rej_parts, srv_parts = [], []
             for g, grp in enumerate(groups):
@@ -1276,7 +1305,10 @@ class RoundEngine:
             method_state=self.group_stack(mstate), key=key,
             round=jnp.asarray(0, jnp.int32),
             losses_ns=jnp.ones((self.N, self.S), jnp.float32),
-            client_mask=(self.world if world is None else world).client_mask,
+            # a copy of the engine's own mask: ``rollout`` donates the
+            # state, and the engine's buffer must outlive it
+            client_mask=(jnp.copy(self.world.client_mask) if world is None
+                         else world.client_mask),
             task_group=jnp.asarray(self._task_group_np),
             task_slot=jnp.asarray(self._task_slot_np))
 
@@ -1380,22 +1412,24 @@ class RoundEngine:
                         if strat.needs_grad_norms else None)
 
         # ---- 2) sampling -------------------------------------------------
-        ctx = self.sampler_ctx(state.round, world)
-        if self.probabilities_hook is not None:
-            p = self.probabilities_hook(ctx, losses_ns, norms_ns)
-        else:
-            p = strat.probabilities(ctx, losses_ns, norms_ns)     # [V,S]
-        # the engine-level mask guarantee: whatever the strategy (or a
-        # pinned probabilities hook) returns, padding processors carry no
-        # probability and draw no participation
-        p = p * w.proc_mask[:, None]
-        active = strat.sample(k_sample, p, ctx, losses_ns)
-        active = active * w.proc_mask[:, None]
+        with jax.named_scope("mmfl.sample"):
+            ctx = self.sampler_ctx(state.round, world)
+            if self.probabilities_hook is not None:
+                p = self.probabilities_hook(ctx, losses_ns, norms_ns)
+            else:
+                p = strat.probabilities(ctx, losses_ns, norms_ns)  # [V,S]
+            # the engine-level mask guarantee: whatever the strategy (or a
+            # pinned probabilities hook) returns, padding processors carry
+            # no probability and draw no participation
+            p = p * w.proc_mask[:, None]
+            active = strat.sample(k_sample, p, ctx, losses_ns)
+            active = active * w.proc_mask[:, None]
 
-        # ---- 3) Sec. 3.3 monitors (shared by BOTH task paths) -----------
-        # computed here, from the sampling arrays the two paths already
-        # share bitwise, so fused == loop holds for metrics by construction
-        metrics = self.sampling_metrics(p, active, losses_ns, world)
+            # ---- 3) Sec. 3.3 monitors (shared by BOTH task paths) -------
+            # computed here, from the sampling arrays the two paths
+            # already share bitwise, so fused == loop holds for metrics by
+            # construction
+            metrics = self.sampling_metrics(p, active, losses_ns, world)
 
         # ---- 4) fused per-task round ------------------------------------
         d_v_t = w.d[w.proc_client] if explicit else self._d_v
@@ -1404,7 +1438,8 @@ class RoundEngine:
         cmask_t = w.client_mask if explicit else self.world.client_mask
         fault_ns = None
         if self.faulty:
-            fault_ns = self._fault_cols(state.key, state.round)
+            with jax.named_scope("mmfl.aggregate"):
+                fault_ns = self._fault_cols(state.key, state.round)
         if fused:
             new_params, new_mstate = [], []
             beta_parts = []
@@ -1508,15 +1543,16 @@ class RoundEngine:
         reused for the output state): rebind the result, don't reuse the
         argument."""
         n_rounds = int(n_rounds)
-        fn = self._rollout_cache.get(n_rounds)
-        if fn is None:
-            fn = (self._sharded_rollout(n_rounds)
-                  if self.mesh is not None
-                  else jax.jit(self._rollout_fn(n_rounds),
-                               donate_argnums=0))
-            self._rollout_cache[n_rounds] = fn
-        return (fn(state, self.world.data) if self.mesh is not None
-                else fn(state))
+        with TraceAnnotation("mmfl.rollout", rounds=n_rounds):
+            fn = self._rollout_cache.get(n_rounds)
+            if fn is None:
+                fn = (self._sharded_rollout(n_rounds)
+                      if self.mesh is not None
+                      else jax.jit(self._rollout_fn(n_rounds),
+                                   donate_argnums=0))
+                self._rollout_cache[n_rounds] = fn
+            return (fn(state, self.world.data) if self.mesh is not None
+                    else fn(state))
 
     def run_seeds(self, seeds: Any, n_rounds: int
                   ) -> Tuple[ExperimentState, Dict[str, jnp.ndarray],
@@ -1628,6 +1664,7 @@ class RoundEngine:
         return fn(worlds, seeds)
 
     # ------------------------------------------------------------------
+    @jax.named_scope("mmfl.evaluate")
     def evaluate_fn(self, state: ExperimentState,
                     world: Optional[World] = None) -> jnp.ndarray:
         """[S] test accuracies as a pure function (vmap-safe): one vmapped
@@ -1646,6 +1683,7 @@ class RoundEngine:
         return accs
 
     def evaluate(self, state: ExperimentState) -> List[float]:
-        return [float(self.eval_jit[s](self.task_params(state, s),
-                                       self.task_test(s)))
-                for s in range(self.S)]
+        with TraceAnnotation("mmfl.evaluate"):
+            return [float(self.eval_jit[s](self.task_params(state, s),
+                                           self.task_test(s)))
+                    for s in range(self.S)]

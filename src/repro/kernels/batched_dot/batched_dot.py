@@ -60,5 +60,6 @@ def batched_dot(G: jnp.ndarray, h: jnp.ndarray, block_p: int | None = None,
         out_specs=[acc, acc],
         out_shape=[jax.ShapeDtypeStruct((C, 8, LANES), jnp.float32)] * 2,
         interpret=interpret,
+        name="batched_dot",
     )(to_rows(G, rows), to_rows(h, rows))
     return jnp.sum(dots, axis=(1, 2)), jnp.sum(norms, axis=(1, 2))

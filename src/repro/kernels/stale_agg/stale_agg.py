@@ -147,6 +147,7 @@ def stale_agg_refresh(coeff: jnp.ndarray, beta: jnp.ndarray,
         # operand indices count the 4 scalar-prefetch args: G=4, h=5
         input_output_aliases={5: 1},
         interpret=interpret,
+        name="stale_agg_refresh",
     )(idx.astype(jnp.int32), coeff.astype(jnp.float32),
       beta.astype(jnp.float32), act.astype(jnp.float32),
       to_rows(G, rows), to_rows(h, rows), to_rows(stale_sum, rows))
