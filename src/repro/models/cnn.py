@@ -43,9 +43,32 @@ def _conv(p, x):
     return y + p["b"]
 
 
+def _pool_reshaped(x):
+    n, h, w, c = x.shape
+    x = x[:, :h // 2 * 2, :w // 2 * 2]
+    return x.reshape(n, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
+
+
+@jax.custom_jvp
 def _pool(x):
+    """2x2 stride-2 max-pool, VALID (an odd last row or column is dropped).
+
+    Forward-only (loss probe, evaluation: shared params over a large batch)
+    it is a reduce_window, which the TPU fuses into the conv's output as laid
+    out; a reshape there splits the batch on the lanes and copies the whole
+    activation.  Differentiated (local SGD, vmapped over per-client params)
+    it is a reshape-and-max: the grouped conv puts W on the lanes, where a
+    reduce_window strides along them and its backward is a
+    select-and-scatter, while the reshape lowers to plain reduces.  Both
+    take the max of the same four values; a gradient at a tie is split
+    evenly rather than given to the first."""
     return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+@_pool.defjvp
+def _pool_jvp(primals, tangents):
+    return jax.jvp(_pool_reshaped, primals, tangents)
 
 
 def apply(params, x: jnp.ndarray) -> jnp.ndarray:

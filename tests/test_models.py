@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import ARCHS, get_config
-from repro.models import transformer
+from repro.models import cnn, transformer
 
 ARCH_IDS = sorted(ARCHS)
 
@@ -191,3 +191,78 @@ def test_logits_shape_contract(arch, rng):
         lambda p, b: transformer.logits(p, cfg, b, q_chunk=16))(params, batch)
     assert lg.shape == (B, S, cfg.vocab_size)
     assert np.all(np.isfinite(np.asarray(lg, np.float32)))
+
+
+# ---------------------------------------------------------------------------
+# the CNN's max-pool, differentiated as a reshape-and-max, pinned against
+# reduce_window's VALID 2x2 stride-2 max and that max's own derivative
+# ---------------------------------------------------------------------------
+
+
+def _pool_rw(x):
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
+                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+def _pool_case_vmapped_grad():
+    """A ReLU'd conv of per-task x per-client params (cnn3-like, small),
+    pooled under the engine's vmap(vmap(value_and_grad)): the pooled values
+    and the gradient that reaches the conv's output.  (The parameter
+    gradients sum that one further, and XLA may order those sums
+    differently around either form.)"""
+    T, A, B = 2, 3, 4
+    keys = jax.random.split(jax.random.PRNGKey(3), T * A).reshape(T, A, -1)
+    params = jax.vmap(jax.vmap(lambda k: cnn.init(k, 10, 8)["conv1"]))(keys)
+    x = jax.random.normal(jax.random.PRNGKey(4), (T, A, B, 28, 28, 1))
+    z = jax.vmap(jax.vmap(cnn._conv))(params, x)
+    wts = jnp.cos(jnp.arange(B * 14 * 14 * 8.0)).reshape(B, 14, 14, 8)
+
+    def run(pool):
+        def loss(zc):
+            h = pool(jax.nn.relu(zc))
+            return jnp.vdot(h, wts), h
+        (_, h), g = jax.jit(jax.vmap(jax.vmap(
+            jax.value_and_grad(loss, has_aux=True))))(z)
+        return h, g
+
+    return run(cnn._pool), run(_pool_rw)
+
+
+def _pool_case_odd_side():
+    """7x7 -> 3x3: the last row and column are dropped, as VALID does."""
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 7, 7, 3))
+
+    def run(pool):
+        y, vjp = jax.vjp(pool, x)
+        assert y.shape == (2, 3, 3, 3)
+        return y, vjp(jnp.ones_like(y))
+
+    return run(cnn._pool), run(_pool_rw)
+
+
+def _pool_case_zero_window():
+    """A window that ReLU zeroes entirely pools to 0 with a zero gradient."""
+    z = jax.random.uniform(jax.random.PRNGKey(6), (1, 4, 4, 2),
+                           minval=0.1, maxval=1.0)
+    z = z.at[0, :2, :2, :].set(-1.0)
+
+    def run(pool):
+        y, g = jax.value_and_grad(
+            lambda a: jnp.sum(pool(jax.nn.relu(a))))(z)
+        out = pool(jax.nn.relu(z))
+        assert np.all(np.asarray(out[0, 0, 0]) == 0.0)
+        assert np.all(np.asarray(g[0, :2, :2]) == 0.0)
+        return out, (y, g)
+
+    return run(cnn._pool), run(_pool_rw)
+
+
+@pytest.mark.parametrize("case", [_pool_case_vmapped_grad,
+                                  _pool_case_odd_side,
+                                  _pool_case_zero_window],
+                         ids=["vmapped_grad", "odd_side", "zero_window"])
+def test_cnn_pool_matches_reduce_window(case):
+    got, want = case()
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

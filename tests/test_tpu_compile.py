@@ -97,3 +97,40 @@ def test_flash_attention_compiles(one_chip, shape, dtype):
     x = _sds(one_chip, shape, dtype)
     text = _compiled_text(flash_attention, x, x, x)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("program", ["local_sgd", "loss_probe"])
+def test_cnn_pool_lowering(one_chip, program):
+    """The Sec. 6.1 CNN's max-pool as the engine runs it.  Local training
+    (3 tasks x 24 cohort slots, 5 SGD steps of 16 images, per-client params)
+    must not lower it to a lane-strided ``reduce-window`` nor its backward to
+    a ``select-and-scatter``; the forward-only loss probe (3 tasks x 120
+    clients x 64 rows, shared params) keeps the ``reduce-window`` fused into
+    conv1's output, where a reshape-and-max would relayout the activation."""
+    T = 3
+    params = jax.eval_shape(
+        jax.vmap(lambda k: cnn.init(k, 10, 8)),
+        jax.random.split(jax.random.PRNGKey(0), T))
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape), params)
+    if program == "local_sgd":
+        A, E, B = 24, 5, 16
+
+        def local_sgd(p0, x, y):
+            def step(p, b):
+                g = jax.grad(cnn.loss_fn)(p, {"x": b[0], "y": b[1]})
+                return jax.tree.map(lambda a, d: a - 0.05 * d, p, g), None
+            return jax.lax.scan(step, p0, (x, y))[0]
+
+        fn = jax.vmap(jax.vmap(local_sgd, in_axes=(None, 0, 0)))
+        lead = (T, A, E, B)
+    else:
+        N, R = 120, 64
+        fn = jax.vmap(jax.vmap(
+            lambda p, x, y: cnn.loss_fn(p, {"x": x, "y": y}),
+            in_axes=(None, 0, 0)))
+        lead = (T, N, R)
+    text = _compiled_text(fn, params,
+                          _sds(one_chip, lead + (28, 28, 1)),
+                          _sds(one_chip, lead, jnp.int32))
+    assert ("reduce-window" in text) == (program == "loss_probe")
+    assert "select-and-scatter" not in text
