@@ -11,22 +11,13 @@ from typing import Any, Dict, Sequence
 
 import numpy as np
 
-
-def cnn_forward_flops(n_classes: int, channels: int, in_ch: int = 1,
-                      side: int = 28, hidden: int = 128) -> int:
-    """Per sample: conv1, conv2 (3x3, SAME, the second at half the side
-    after pooling), fc1, fc2."""
-    c2, half, flat = 2 * channels, side // 2, (side // 4) ** 2 * 2 * channels
-    return (side * side * 9 * in_ch * channels * 2
-            + half * half * 9 * channels * c2 * 2
-            + flat * hidden * 2
-            + hidden * n_classes * 2)
+from world import load_kind
 
 
 def forward_flops(spec: Dict[str, Any]) -> int:
-    if spec["kind"] != "image":
-        raise ValueError(f"no operation count for kind {spec['kind']!r}")
-    return cnn_forward_flops(spec["n_classes"], spec["channels"])
+    """Per sample, one task's model: its kind's count
+    (``bench/kinds/<kind>.py``)."""
+    return load_kind(spec["kind"]).forward_flops(spec)
 
 
 def round_flops(specs: Sequence[Dict[str, Any]], counts: np.ndarray,

@@ -4,8 +4,9 @@ engine's documented conventions, importing nothing of the program.
 It follows the same random streams as the engine (``jax.random`` keys
 split and folded in the documented order), so that from the same seed it
 draws the same cohorts and minibatches, and it computes everything else
-plainly: models in ``jax.numpy`` at the configuration's matmul precision,
-the water-filling solve and the inverse-CDF draw in float64 numpy.
+plainly: models in ``jax.numpy`` at the configuration's matmul precision
+(each task's from its kind's module, ``bench/kinds/<kind>.py``), the
+water-filling solve and the inverse-CDF draw in float64 numpy.
 
 Methods: ``lvr`` (Thm 2/9 sampling, Eq. 3 aggregation) and ``stalevre``
 (beta of Eq. 20 measured on active clients and extrapolated by Eq. 21 for
@@ -31,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from world import load_kind
+
 SEARCH = 1e-4         # |u - cdf| under which a draw's side is looked at
 MAX_NEAR = 8          # near draws looked at per round, the nearest first
 UTILITY_FLOOR = 1e-8  # Assumption 5: the floor on a utility
@@ -40,59 +43,16 @@ PROBE_TAKE = 64
 # ---------------------------------------------------------------------------
 # models
 # ---------------------------------------------------------------------------
-def cnn_init(key, n_classes: int, channels: int, in_ch: int = 1):
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-
-    def conv(k, cin, cout):
-        s = 1.0 / math.sqrt(9 * cin)
-        return jax.random.uniform(k, (3, 3, cin, cout), jnp.float32, -s, s)
-
-    c2, hidden = 2 * channels, 128
-    flat = 7 * 7 * c2
-    sf, sh = 1 / math.sqrt(flat), 1 / math.sqrt(hidden)
-    return {
-        "conv1": {"w": conv(k1, in_ch, channels),
-                  "b": jnp.zeros((channels,), jnp.float32)},
-        "conv2": {"w": conv(k2, channels, c2),
-                  "b": jnp.zeros((c2,), jnp.float32)},
-        "fc1": {"w": jax.random.uniform(k3, (flat, hidden), jnp.float32,
-                                        -sf, sf),
-                "b": jnp.zeros((hidden,), jnp.float32)},
-        "fc2": {"w": jax.random.uniform(k4, (hidden, n_classes),
-                                        jnp.float32, -sh, sh),
-                "b": jnp.zeros((n_classes,), jnp.float32)},
-    }
-
-
-def cnn_logits(p, x):
-    """2 conv (3x3, SAME) + 2 max-pool (2x2) + 2 linear, ReLU between."""
-    def conv(q, h):
-        return jax.lax.conv_general_dilated(
-            h, q["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + q["b"]
-
-    def pool(h):
-        n, hh, ww, c = h.shape
-        return h.reshape(n, hh // 2, 2, ww // 2, 2, c).max(axis=(2, 4))
-
-    h = pool(jax.nn.relu(conv(p["conv1"], x)))
-    h = pool(jax.nn.relu(conv(p["conv2"], h)))
-    h = jax.nn.relu(h.reshape(h.shape[0], -1) @ p["fc1"]["w"]
-                    + p["fc1"]["b"])
-    return h @ p["fc2"]["w"] + p["fc2"]["b"]
-
-
 def cross_entropy(logits, y):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, y[..., None], -1))
 
 
 def model_fns(spec: Dict[str, Any]):
-    """(init(key), logits(params, x)) for one task's configuration entry."""
-    if spec["kind"] != "image":
-        raise ValueError(f"no reference model for kind {spec['kind']!r}")
-    return (lambda k: cnn_init(k, spec["n_classes"], spec["channels"]),
-            cnn_logits)
+    """(init(key), logits(params, x)) for one task's configuration entry:
+    its kind's plain model (``bench/kinds/<kind>.py``)."""
+    kind = load_kind(spec["kind"])
+    return (lambda k: kind.init(k, spec), kind.logits)
 
 
 # ---------------------------------------------------------------------------

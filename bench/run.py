@@ -10,7 +10,8 @@ cadence).  The run
   1. builds the world from the benchmark's own generators
      (``bench/world.py``, fixed by the configuration's ``data_seed``) and
      hands it to the program's ``RoundEngine`` with the program's model
-     adapters;
+     adapters; a task's ``kind`` names the module that gives both,
+     ``bench/kinds/<kind>.py``;
   2. sets up: ``init_state(seed)``, then one chunk of the timed loop (a
      ``rollout`` of ``eval_every`` rounds and an ``evaluate``), which
      compiles or loads every program the window uses and whose results
@@ -24,9 +25,10 @@ cadence).  The run
 
 With ``--trace 1`` the window runs under the profiler and the run reports
 the per-layer metrics instead, each computed by its reader
-``bench/metrics/<metric>.py``.  The last line of standard output is the
-result as JSON.  Without a TPU, or with fewer chips than the cell asks
-for, the run exits 3 and prints no result.
+``bench/metrics/<metric>.py`` from the trace's reduction
+(``bench/trace_reduce.py``, ``bench/trace_scopes.py``).  The last line of
+standard output is the result as JSON.  Without a TPU, or with fewer chips
+than the cell asks for, the run exits 3 and prints no result.
 """
 from __future__ import annotations
 
@@ -98,16 +100,17 @@ class CompileCounter:
 
 def make_engine(cfg, traffic, world_np, seed: int):
     """The program under test: ``RoundEngine`` over the benchmark's world,
-    with the program's own model adapters."""
+    with the program's own model adapters (each task's from its kind's
+    ``adapter``, ``bench/kinds/<kind>.py``)."""
     import jax.numpy as jnp
     from repro.core.engine import RoundEngine, ServerConfig, Task
-    from repro.fl.experiments import _cnn_adapter
+    from world import load_kind
 
     tasks_np, B, avail = world_np
     tasks = []
     for t in tasks_np:
         sp = t["spec"]
-        model = _cnn_adapter(sp["n_classes"], sp["channels"])
+        model = load_kind(sp["kind"]).adapter(sp)
         tasks.append(Task(name=sp["name"], model=model,
                           data={k: jnp.asarray(v)
                                 for k, v in t["data"].items()},
@@ -140,6 +143,18 @@ def load_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def read_trace(path: str, rounds: int):
+    """The traced window's ``.xplane.pb``, read once: the reduction the
+    trace's readers share (``trace_reduce.Summary``) and the per-phase
+    device times and idle split (``trace_scopes.summarize``) of its
+    ``rounds`` rounds."""
+    import trace_reduce as tr
+    import trace_scopes
+    events = tr.read_events(path)
+    return tr.Summary(events), trace_scopes.summarize(
+        events, trace_scopes.program_op_names(path), rounds)
 
 
 def cell_metrics(bench, cell, kind: str):
@@ -281,10 +296,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     ctx = {"cfg": cfg, "world": world_np, "rounds": rounds,
            "chunks": chunks, "window_s": window_s, "eval_s": eval_s,
            "warmup_s": warmup_s, "trained": trained, "peak": peak,
-           "trace": None}
+           "trace": None, "phases": None}
     if trace:
         import trace_reduce as tr
-        summary = tr.Summary(tr.read_events(tr.find_xplane(TRACE_DIR)))
+        summary, ctx["phases"] = read_trace(tr.find_xplane(TRACE_DIR),
+                                            rounds)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         ctx["trace"] = summary
         result["device"]["busy_s"] = summary.busy_s

@@ -4,6 +4,8 @@ function recorded on the CPU (whose XLA operations run on host threads and
 carry their program id as a stat, where a TPU trace has an ``XLA Modules``
 line)."""
 import json
+import math
+import os
 import shutil
 import time
 
@@ -11,8 +13,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import run
 import trace_reduce as tr
 import trace_scopes as ts
+from conftest import ROOT
 
 TPU = "/device:TPU:0"
 
@@ -227,3 +231,36 @@ def test_cli_reads_a_recorded_trace(cpu_trace, capsys):
     out = json.loads(capsys.readouterr().out)
     assert out["window_s"] > 0 and out["rounds"] == 0
     assert out["round.stats.device_ms"] is None
+
+
+# the per-layer metrics read from ``trace_scopes``'s reduction
+PHASE_METRICS = ("round.stats.device_ms", "round.sample.device_ms",
+                 "round.local_train.device_ms", "round.aggregate.device_ms",
+                 "round.unscoped_share", "evaluate.device_ms",
+                 "idle.between_programs_share", "idle.in_program_share")
+
+
+def test_phase_readers_read_the_reduction():
+    """Each reader returns its number of the reduction, and each is a
+    per-layer metric of ``BENCHMARK.json``."""
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    assert set(PHASE_METRICS) <= {m["name"] for m in bench["per_layer"]}
+    phases = ts.summarize(_events(), OP_NAMES, rounds=5)
+    for name in PHASE_METRICS:
+        assert run.load_reader(name)({"phases": phases}) == phases[name]
+    assert run.load_reader("round.local_train.device_ms")(
+        {"phases": phases}) == pytest.approx(1e3 * 45e-9 / 5)
+    assert run.load_reader("round.stats.device_ms")({"phases": None}) is None
+
+
+def test_traced_branch_reaches_the_readers(cpu_trace):
+    """The recorded trace through ``run.read_trace``, as the traced run
+    reads its window before deleting the trace, then through each reader:
+    a finite number, or None where the CPU trace has no scope to read."""
+    summary, phases = run.read_trace(cpu_trace, rounds=6)
+    assert isinstance(summary, tr.Summary) and summary.window_s > 0
+    assert phases["rounds"] == 6 and phases["window_s"] > 0
+    ctx = {"trace": summary, "phases": phases, "rounds": 6}
+    for name in PHASE_METRICS:
+        value = run.load_reader(name)(ctx)
+        assert value is None or math.isfinite(value), (name, value)
