@@ -63,7 +63,10 @@ all-gather when sharded), ``mmfl.local_train`` (cohort gather and local
 SGD, the all-client training of needs-all methods too), ``mmfl.aggregate``
 (coefficients, fault inject and guard, the strategy's aggregation and its
 kernels and psum) and ``mmfl.evaluate`` (test accuracy).  An operation
-belongs to the innermost of these in its name stack.  On the host,
+belongs to the innermost of these in its name stack.  In the fused round
+each signature group's stats and round bodies sit inside the scope
+``group.<first task name>`` (``group_scope``), outside the phase names,
+so an operation keeps its phase and also names its group.  On the host,
 ``rollout`` and ``evaluate`` run under the ``TraceAnnotation`` spans
 ``mmfl.rollout`` (with its number of ``rounds``) and ``mmfl.evaluate``;
 ``bench/trace_scopes.py`` reduces such a trace to device time per phase.
@@ -794,6 +797,11 @@ class RoundEngine:
     # ------------------------------------------------------------------
     # fused task axis: group-level pure computations (one vmap per group)
     # ------------------------------------------------------------------
+    def group_scope(self, g: int) -> str:
+        """Name scope of signature group g's stats and round bodies in the
+        fused round: ``group.<its first task's name>``."""
+        return f"group.{self.tasks[self.groups[g][0]].name}"
+
     def make_group_stats_fn(self, g: int) -> Callable:
         """The stats phase for signature group g as ONE vmapped dispatch
         over the group's stacked (params, data, keys).  Per-task streams
@@ -825,6 +833,7 @@ class RoundEngine:
                     stale.batched_tree_dot(G, G), 0.0))
             return losses, G, norms
 
+        @jax.named_scope(self.group_scope(g))
         @jax.named_scope("mmfl.stats")
         def stats_g(params_g, data_g, keys_g, lr, explicit=False):
             px, py = ((data_g["x"][:, :, :take], data_g["y"][:, :, :take])
@@ -851,6 +860,7 @@ class RoundEngine:
         round_one = self.make_round_fn(grp[0],
                                        local_all=self._local_all[grp[0]])
 
+        @jax.named_scope(self.group_scope(g))
         def round_g(params_g, state_g, train_in_g, p_g, act_g,
                     data_g, lr, round_idx, view_g, fault_g=None):
             if len(grp) == 1:
@@ -1006,6 +1016,7 @@ class RoundEngine:
                     stale.batched_tree_dot(G, G), 0.0))
             return losses, G, norms
 
+        @jax.named_scope(self.group_scope(g))
         @jax.named_scope("mmfl.stats")
         def stats_g(params_g, data_g, keys_g, lr, off):
             if len(grp) == 1:
@@ -1094,6 +1105,7 @@ class RoundEngine:
                 extras["rejected"], extras["survived"] = fault_counts
             return new_w, new_st, extras
 
+        @jax.named_scope(self.group_scope(g))
         def round_g(params_g, state_g, train_in_g, p_g, act_g,
                     data_g, lr, round_idx, view_g, off, fault_g=None):
             if len(grp) == 1:

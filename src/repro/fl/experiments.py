@@ -32,17 +32,21 @@ from repro.data import partition, synthetic
 from repro.models import cnn, lstm, transformer
 
 
-def _cnn_adapter(n_classes: int, channels: int, in_ch: int = 1) -> ModelAdapter:
+def _cnn_adapter(n_classes: int, channels: int, in_ch: int = 1,
+                 side: int = 28) -> ModelAdapter:
     return ModelAdapter(
-        init=lambda key: cnn.init(key, n_classes, channels, in_ch),
+        init=lambda key: cnn.init(key, n_classes, channels, in_ch,
+                                  side=side),
         loss_fn=cnn.loss_fn,
         accuracy=cnn.accuracy,
     )
 
 
-def _lstm_adapter(vocab: int) -> ModelAdapter:
+def _lstm_adapter(vocab: int, d_embed: int = 24,
+                  d_hidden: int = 64) -> ModelAdapter:
     return ModelAdapter(
-        init=lambda key: lstm.init(key, vocab, d_embed=24, d_hidden=64),
+        init=lambda key: lstm.init(key, vocab, d_embed=d_embed,
+                                   d_hidden=d_hidden),
         loss_fn=lstm.loss_fn,
         accuracy=lstm.accuracy,
     )
@@ -50,28 +54,41 @@ def _lstm_adapter(vocab: int) -> ModelAdapter:
 
 def _image_task(rng, name: str, n_clients: int, n_classes: int = 10,
                 channels: int = 8, n_per_class: int = 200,
-                label_frac: float = 0.3) -> Task:
+                label_frac: float = 0.3, side: int = 28,
+                in_ch: int = 1) -> Task:
     x, y = synthetic.make_image_task(rng, n_classes=n_classes,
-                                     n_per_class=n_per_class)
+                                     n_per_class=n_per_class, side=side,
+                                     in_ch=in_ch)
     n_test = max(64, len(y) // 10)
     test = {"x": jnp.asarray(x[:n_test]), "y": jnp.asarray(y[:n_test])}
     part = partition.label_shard_partition(rng, x[n_test:], y[n_test:],
                                            n_clients, label_frac=label_frac)
     data = {k: jnp.asarray(v) for k, v in part.items() if k != "high"}
-    return Task(name=name, model=_cnn_adapter(n_classes, channels),
+    return Task(name=name,
+                model=_cnn_adapter(n_classes, channels, in_ch, side),
                 data=data, test=test)
 
 
-def _char_task(rng, name: str, n_clients: int, vocab: int = 48) -> Task:
+def _char_task(rng, name: str, n_clients: int, vocab: int = 48,
+               seq_len: int = 24, stream_lens: Optional[Sequence[int]] = None,
+               n_test: int = 128, d_embed: int = 24,
+               d_hidden: int = 64) -> Task:
+    """One stream per speaker, of ``stream_lens`` characters each (default:
+    ``max(n_clients + 16, 64)`` speakers of 256); the first ``n_test``
+    sequences are the test set, and each client is one of the remaining
+    speakers (``partition.stream_partition``)."""
+    if stream_lens is None:
+        stream_lens = [256] * max(n_clients + 16, 64)
     x, y, sid = synthetic.make_char_task(rng, vocab=vocab,
-                                         n_streams=max(n_clients + 16, 64),
-                                         stream_len=256, seq_len=24)
-    n_test = 128
+                                         n_streams=len(stream_lens),
+                                         stream_len=stream_lens,
+                                         seq_len=seq_len)
     test = {"x": jnp.asarray(x[:n_test]), "y": jnp.asarray(y[:n_test])}
     part = partition.stream_partition(rng, x[n_test:], y[n_test:],
                                       sid[n_test:], n_clients)
     data = {k: jnp.asarray(v) for k, v in part.items() if k != "high"}
-    return Task(name=name, model=_lstm_adapter(vocab), data=data, test=test)
+    return Task(name=name, model=_lstm_adapter(vocab, d_embed, d_hidden),
+                data=data, test=test)
 
 
 def align_task_caps(tasks: List[Task]) -> List[Task]:

@@ -1,7 +1,7 @@
 """Small CNN matching the paper's §6.1 classifier: 2 conv + 2 pool + 2 linear.
 
 Used by the faithful-reproduction experiments (Fashion-MNIST / EMNIST-like
-synthetic 28x28 tasks, model-specific output sizes)."""
+synthetic 28x28x1 tasks, CIFAR-like 32x32x3, model-specific output sizes)."""
 from __future__ import annotations
 
 import math
@@ -12,7 +12,9 @@ import jax.numpy as jnp
 
 
 def init(key, n_classes: int, channels: int = 16, in_ch: int = 1,
-         dtype=jnp.float32) -> Dict:
+         dtype=jnp.float32, side: int = 28) -> Dict:
+    """Params for ``side`` x ``side`` x ``in_ch`` inputs (28x28x1 for the
+    Fashion-MNIST/EMNIST-like tasks, 32x32x3 for CIFAR-10)."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
 
     def conv_init(k, kh, kw, cin, cout):
@@ -20,7 +22,7 @@ def init(key, n_classes: int, channels: int = 16, in_ch: int = 1,
         return jax.random.uniform(k, (kh, kw, cin, cout), dtype, -scale, scale)
 
     c2 = channels * 2
-    flat = 7 * 7 * c2  # 28 -> pool -> 14 -> pool -> 7
+    flat = (side // 4) ** 2 * c2  # two 2x2 pools: 28 -> 7, 32 -> 8
     hidden = 128
     return {
         "conv1": {"w": conv_init(k1, 3, 3, in_ch, channels),
@@ -72,7 +74,7 @@ def _pool_jvp(primals, tangents):
 
 
 def apply(params, x: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, 28, 28, C] -> logits [B, n_classes]."""
+    """x: [B, side, side, in_ch] -> logits [B, n_classes]."""
     h = jax.nn.relu(_conv(params["conv1"], x))
     h = _pool(h)
     h = jax.nn.relu(_conv(params["conv2"], h))

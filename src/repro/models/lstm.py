@@ -1,5 +1,8 @@
 """Character-level LSTM language model (paper §6.1 Shakespeare task):
-embedding + 2-layer LSTM + linear head.  Pure JAX (lax.scan over time)."""
+embedding + 2-layer LSTM + linear head.  Pure JAX (lax.scan over time,
+under the scope ``lstm.recurrence``).  Gates i, f, g, o stacked in that
+order on the last axis of ``wx``/``wh``/``b``; no peepholes; zero initial
+state."""
 from __future__ import annotations
 
 import math
@@ -56,7 +59,9 @@ def apply(params, tokens: jnp.ndarray) -> jnp.ndarray:
 
     zeros = jnp.zeros((B, d_h), x.dtype)
     init_carry = ((zeros, zeros), (zeros, zeros))
-    _, ys = jax.lax.scan(step, init_carry, x.swapaxes(0, 1))
+    # the serial part of the model, named for a profiler trace
+    with jax.named_scope("lstm.recurrence"):
+        _, ys = jax.lax.scan(step, init_carry, x.swapaxes(0, 1))
     h = ys.swapaxes(0, 1)                                   # [B,S,H]
     return h @ params["head"]["w"] + params["head"]["b"]
 

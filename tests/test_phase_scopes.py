@@ -6,6 +6,10 @@ A profiler trace ties a device operation to a round phase through the
 on the CPU.  That the scopes change no number is pinned by the bitwise
 batteries (tests/test_task_fusion.py, tests/test_world_padding.py,
 tests/test_sharding.py and the goldens of tests/test_methods.py)."""
+import contextlib
+import os
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -68,3 +72,73 @@ def test_second_init_state_after_rollout():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(eng.world.client_mask),
                                   np.asarray(second.client_mask))
+
+
+class _NoScope(contextlib.ContextDecorator):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*op_name="([^"]*)"')
+
+
+def _cnn_lstm_engine():
+    """Two tiny CNN tasks (one signature group) and a tiny char LSTM."""
+    from repro.fl.experiments import (_char_task, _image_task,
+                                      align_task_caps)
+    rng = np.random.default_rng(0)
+    n = 8
+    tasks = [_image_task(rng, f"img-{i}", n, n_classes=4, channels=2,
+                         n_per_class=20, side=8) for i in range(2)]
+    tasks.append(_char_task(rng, "chars", n, vocab=12, seq_len=6,
+                            stream_lens=[40] * (n + 4), n_test=8,
+                            d_embed=4, d_hidden=8))
+    B = np.full(n, 2)
+    avail = np.ones((n, 3), bool)
+    cfg = ServerConfig(method="lvr", local_epochs=2, seed=1,
+                       active_rate=0.3, batch_size=4)
+    return RoundEngine(align_task_caps(tasks), B, avail, cfg)
+
+
+def _instr_op_names(eng):
+    state = eng.init_state(seed=3)
+    text = jax.jit(lambda s: eng.round_step_fn(s)).lower(
+        state).compile().as_text()
+    return dict(m.groups() for m in map(_INSTR.match, text.splitlines())
+                if m)
+
+
+def test_group_and_recurrence_scopes_keep_every_phase(monkeypatch):
+    """A CNN-plus-LSTM world's compiled round names the LSTM's time scan
+    (``lstm.recurrence``) and each signature group (``group.<first task
+    name>``), and every operation keeps the phase
+    (``bench/trace_scopes.phase_of``) it has without those scopes."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    monkeypatch.syspath_prepend(bench)
+    import trace_scopes
+
+    eng = _cnn_lstm_engine()
+    assert eng.groups == [[0, 1], [2]]
+    scoped = _instr_op_names(eng)
+    names = list(scoped.values())
+    for scope in ("lstm.recurrence", "group.img-0", "group.chars"):
+        assert any(scope in n for n in names), scope
+    assert any("group.chars" in n and "lstm.recurrence" in n
+               and trace_scopes.phase_of(n) == "local_train" for n in names)
+    assert any("group.chars" in n and "lstm.recurrence" in n
+               and trace_scopes.phase_of(n) == "stats" for n in names)
+
+    plain_scope = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: (
+        _NoScope() if name.startswith(("group.", "lstm.")) else
+        plain_scope(name)))
+    bare = _instr_op_names(_cnn_lstm_engine())
+    assert not any("group." in n or "lstm." in n for n in bare.values())
+    assert scoped.keys() == bare.keys()
+    for instr, name in scoped.items():
+        assert (trace_scopes.phase_of(name)
+                == trace_scopes.phase_of(bare[instr])), instr
